@@ -1,11 +1,13 @@
-"""Fleet benchmark: sequential vs interleaved execution, a fleet-size
-scaling sweep, and a device-count sweep over the mesh-sharded runtime.
+"""Fleet benchmark: sequential vs interleaved execution and a fleet-size
+scaling sweep.
 
-Three experiments, all subprocess-isolated (jax jit caches are module-
-and process-level, so timing two configurations in one process hands
-whichever runs second a fully warmed cache and biases every ratio; a
-forced host device count additionally *must* be set before jax first
-initializes, which only a fresh process can do):
+Two experiments, each configuration in a child process of its own (jax
+jit caches are module- and process-level, so timing two configurations
+in one process hands whichever runs second a fully warmed cache and
+biases every ratio). The parent never touches JAX, so each child in
+turn can hold the chip; the host/device block of the payload comes from
+a child. The four-chip check of the mesh-sharded runtime is
+``chip_smoke.py --chips 4``.
 
   comparison   the original 8-query / 3-camera mixed workload run
                sequentially (each executor's ``run()`` to completion —
@@ -20,12 +22,6 @@ initializes, which only a fresh process can do):
                frames-per-dispatch / watermark fires / overlap and full
                ``dispatch_stats`` per point so regressions are
                attributable to a layer.
-  device_scaling  the 8-query workload re-run under forced host device
-               counts (``--xla_force_host_platform_device_count``);
-               simulated results (``done_t``) and ``traces_per_arch``
-               must be identical at every device count — device
-               parallelism is an execution detail, not a semantics
-               knob.
 
 On single-core hosts the score/uplink overlap term is structurally
 zero (device compute and the host tick loop timeshare one core), so
@@ -224,25 +220,27 @@ def run_point(n_queries: int, hours: float, train_steps: int) -> dict:
 
 
 def _emit(call: str, out_path: str, **kw):
+    """A child's side of ``_subprocess``: run one configuration and
+    write its result, with the host/device block, as JSON."""
+    from benchmarks.common import host_meta
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     out = {"mode": run_mode, "point": run_point}[call](**kw)
+    out["host"] = host_meta()
     Path(out_path).write_text(json.dumps(out))
 
 
-def _subprocess(call: str, *, device_count: int | None = None, **kw) -> dict:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = str(ROOT / "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    if device_count is not None:
-        env["XLA_FLAGS"] = \
-            f"--xla_force_host_platform_device_count={device_count}"
-        env["JAX_PLATFORMS"] = "cpu"
+def _subprocess(call: str, **kw) -> dict:
+    from benchmarks.run import child_env
+
     with tempfile.NamedTemporaryFile(suffix=".json", delete=False) as f:
         out_path = f.name
     try:
         code = ("from benchmarks.bench_fleet import _emit; "
                 f"_emit({call!r}, {out_path!r}, **{kw!r})")
-        subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
-                       check=True)
+        subprocess.run([sys.executable, "-c", code], cwd=ROOT,
+                       env=child_env(), check=True)
         return json.loads(Path(out_path).read_text())
     finally:
         os.unlink(out_path)
@@ -257,7 +255,9 @@ def run_comparison(hours: float, train_steps: int) -> dict:
                         train_steps=train_steps)
     assert fleet.pop("done_t") == seq.pop("done_t"), \
         "uncontended fleet must match sequential simulated completion"
+    seq.pop("host")
     return {
+        "host": fleet.pop("host"),
         "queries": len(WORKLOAD),
         "cameras": len(CAMERAS),
         "sequential": seq,
@@ -275,6 +275,7 @@ def run_scaling(sizes, hours: float, train_steps: int) -> list:
         t0 = time.time()
         point = _subprocess("point", n_queries=n, hours=hours,
                             train_steps=train_steps)
+        point.pop("host")
         point["subprocess_wall_s"] = round(time.time() - t0, 1)
         print(f"[bench] scaling point {n}q: wall_s={point['wall_s']} "
               f"dispatches={point['dispatches']} "
@@ -284,41 +285,8 @@ def run_scaling(sizes, hours: float, train_steps: int) -> list:
     return curve
 
 
-def run_device_sweep(counts, hours: float, train_steps: int) -> list:
-    """The 8-query workload under forced host device counts.  Simulated
-    results and per-arch trace counts must be device-count-invariant;
-    wall-clock is whatever the host gives (on a single physical core,
-    forced devices timeshare and add partition overhead — the point of
-    recording the curve is that on real multi-core hosts it bends the
-    other way)."""
-    sweep = []
-    base_done = base_traces = None
-    for d in counts:
-        out = _subprocess("mode", device_count=d, mode="fleet",
-                          hours=hours, train_steps=train_steps)
-        done = out.pop("done_t")
-        if base_done is None:
-            base_done, base_traces = done, out["traces_per_arch"]
-        else:
-            assert done == base_done, \
-                f"device_count={d} changed simulated results"
-            assert out["traces_per_arch"] == base_traces, \
-                f"device_count={d} changed tracing: " \
-                f"{out['traces_per_arch']} vs {base_traces}"
-        keep = ("wall_s", "dispatches", "frames_per_dispatch",
-                "eager_dispatches", "watermark_fires", "overlap_host_s",
-                "result_block_s", "device_count", "mesh_shape", "sharded",
-                "sharding_fallbacks", "dispatch_stats")
-        point = {k: out[k] for k in keep}
-        print(f"[bench] device point d={d}: wall_s={point['wall_s']} "
-              f"sharded={point['sharded']} "
-              f"overlap_host_s={point['overlap_host_s']}", flush=True)
-        sweep.append(point)
-    return sweep
-
-
 def main(profile_name: str = "standard"):
-    from benchmarks.common import host_meta, print_table
+    from benchmarks.common import print_table
     quick = profile_name == "quick"
     hours = 0.25 if quick else 0.5
     # low on purpose: training is identical compute in both modes and
@@ -327,11 +295,9 @@ def main(profile_name: str = "standard"):
     sweep_hours = 0.05 if quick else 0.1
     sweep_steps = 5 if quick else 10
     sizes = (8, 32, 128)
-    counts = (1, 2, 4)
 
     comparison = run_comparison(hours, train_steps)
     scaling = run_scaling(sizes, sweep_hours, sweep_steps)
-    devices = run_device_sweep(counts, sweep_hours, sweep_steps)
 
     rows = [dict(mode=m, **{k: comparison[m][k] for k in
                             ("wall_s", "dispatches", "frames_scored",
@@ -346,11 +312,6 @@ def main(profile_name: str = "standard"):
         [{k: p[k] for k in ("queries", "wall_s", "dispatches",
                             "frames_per_dispatch", "eager_dispatches",
                             "overlap_host_s")} for p in scaling])
-    print_table(
-        "Device-count sweep (8-query workload, forced host devices)",
-        [{k: p[k] for k in ("device_count", "sharded", "wall_s",
-                            "overlap_host_s", "result_block_s")}
-         for p in devices])
     fleet = comparison["fleet"]
     print(f"[bench] fleet speedup: {comparison['speedup']}x wall-clock; "
           f"dispatch reduction: {comparison['dispatch_reduction']}x "
@@ -358,7 +319,7 @@ def main(profile_name: str = "standard"):
           f"{fleet['dispatches']} calls, "
           f"{fleet['eager_dispatches']} issued eagerly, "
           f"watermarks {fleet['watermark_fires']})")
-    host = host_meta()
+    host = comparison.pop("host")
     payload = {
         "benchmark": "fleet",
         "hours": hours,
@@ -368,7 +329,6 @@ def main(profile_name: str = "standard"):
         "host": host,
         **comparison,
         "fleet_scaling": scaling,
-        "device_scaling": devices,
     }
     if host.get("cpu_count") == 1:
         payload["overlap_note"] = (

@@ -24,9 +24,11 @@ from repro.core.hardware import YOLO_V3
 from repro.core.query import Query, make_env
 from repro.core.ranking import RetrievalExecutor
 from repro.core.video import Video, corpus
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     print("== 1. capture (zero streaming) ==")
     video = Video(corpus(hours=1.0)["Banff"])

@@ -16,11 +16,13 @@ import jax
 import numpy as np
 
 from repro.configs.base import ARCH_IDS, get_smoke_config
+from repro.launch import compile_cache
 from repro.models import layers, transformer
 from repro.serving.engine import ServeEngine
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b", choices=ARCH_IDS)
     ap.add_argument("--requests", type=int, default=8)

@@ -21,10 +21,12 @@ import tempfile
 
 sys.path.insert(0, "src")
 
+from repro.launch import compile_cache
 from repro.launch import train as train_mod
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--steps", type=int, default=200)

@@ -34,6 +34,7 @@ from repro.core.hardware import DETECTORS, NetworkModel
 from repro.core.query import Query, make_env
 from repro.core.ranking import RetrievalExecutor
 from repro.core.video import QUERY_CLASS, Video, corpus
+from repro.launch import compile_cache
 
 
 def describe(name, env, prog):
@@ -89,6 +90,7 @@ def run_fleet(n_queries: int, hours: float, uplink_mbps: float,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--video", default="Banff", choices=sorted(QUERY_CLASS))
     ap.add_argument("--kind", default="retrieval",

@@ -8,9 +8,10 @@ presence probability (Retrieval) or predicted count (max-Count);
 filters threshold presence probability with calibrated (lo, hi).
 
 Batched inference goes through ``core/runtime.OperatorRuntime``, which
-jit-compiles one scoring function per arch signature and dispatches the
-conv stack to the Pallas ``kernels/conv_scorer`` kernel on TPU hosts
-(jnp reference fallback on CPU). The unjitted ``apply_operator`` /
+jit-compiles one scoring function per arch signature. On a TPU host the
+conv stack runs in the Pallas ``kernels/conv_scorer`` kernel, which is
+required there: a shape it cannot compile raises, it never falls back.
+CPU hosts run the jnp reference. The unjitted ``apply_operator`` /
 ``score_frames`` below are the mathematical oracle that training and
 the runtime's correctness tests compare against.
 """
@@ -125,17 +126,9 @@ def _loss_fn(params, x, y_present, y_count, train_count: bool):
 _value_and_grad = jax.jit(jax.value_and_grad(_loss_fn),
                           static_argnames=("train_count",))
 
-# m/v (Adam state) and xb are produced fresh every step, so their buffers
-# can be donated where XLA honours it; params must NOT be donated — train
-# is resumable and callers may still be scoring with the incoming params
-# (e.g. an executor running the old operator while its upgrade trains).
-_STEP_DONATE = (1, 2, 3) if _kops.donation_supported() else ()
 
-
-@functools.partial(jax.jit, static_argnames=("train_count",),
-                   donate_argnums=_STEP_DONATE)
-def _adam_step(params, m, v, xb, bright, ypb, ycb, bc1, bc2, decay, lr,
-               train_count: bool):
+def _adam_step_body(params, m, v, xb, bright, ypb, ycb, bc1, bc2, decay, lr,
+                    train_count: bool):
     """One fused train step: brightness augment, value_and_grad, Adam.
 
     A single jit dispatch per step — the previous eager tree_maps cost
@@ -158,6 +151,20 @@ def _adam_step(params, m, v, xb, bright, ypb, ycb, bc1, bc2, decay, lr,
         lr * (m_ / bc1) / (jnp.sqrt(v_ / bc2) + 1e-8),
         params, m, v)
     return params, m, v
+
+
+@functools.cache
+def _adam_step():
+    """The jitted ``_adam_step_body``, built on first use. m/v (Adam
+    state) and xb are produced fresh every step, so their buffers can be
+    donated where XLA honours it; params must NOT be donated — train is
+    resumable and callers may still be scoring with the incoming params
+    (e.g. an executor running the old operator while its upgrade
+    trains). Whether donation is honoured depends on the backend, and
+    asking at import time would initialise it in every importer."""
+    donate = (1, 2, 3) if _kops.donation_supported() else ()
+    return jax.jit(_adam_step_body, static_argnames=("train_count",),
+                   donate_argnums=donate)
 
 
 def train_operator(arch: OperatorArch, params: Optional[dict], crops,
@@ -198,7 +205,7 @@ def train_operator(arch: OperatorArch, params: Optional[dict], crops,
         # must generalize across capture hours
         bright = np.asarray(rng.uniform(0.7, 1.3, (len(sel), 1, 1, 1)),
                             np.float32)
-        params, m, v = _adam_step(
+        params, m, v = _adam_step()(
             params, m, v, x[sel], bright, yp[sel], yc[sel],
             np.float32(1 - 0.9 ** t), np.float32(1 - 0.999 ** t),
             decay, lr32, train_count)

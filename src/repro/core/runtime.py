@@ -59,11 +59,14 @@ from typing import Callable, Dict, List, Optional, Set, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops as kops
 from repro.parallel import sharding as shd
 
 ArchSig = Tuple[int, int, int, int]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 CHUNK = 1024          # frames per dispatch (bounds crop-cache pressure)
 MIN_BUCKET = 64       # smallest padded batch shape (bucketed path)
@@ -112,7 +115,8 @@ class OperatorRuntime:
     ``mesh``: an optional 1-D ``("data",)`` mesh (see
     ``launch/mesh.make_scoring_mesh``). When it holds >1 device, every
     stacked superbatch is placed with a group-axis ``NamedSharding``
-    so XLA partitions the scorer body across devices (GSPMD). Each
+    and its scorer body runs under ``jax.shard_map`` on each device's
+    members (XLA cannot partition a Pallas kernel itself). Each
     group member's full ``(bucket, …)`` computation stays whole on one
     device — exactly the single-device shapes and accumulation order —
     so sharded results are bitwise identical to single-device ones
@@ -192,13 +196,18 @@ class OperatorRuntime:
         cannot change the traced math."""
         conv = kops.conv_scorer_fn(self.backend, interpret=self.interpret)
 
+        def dense(h, layer):
+            # f32 at full precision: a TPU otherwise rounds matmul
+            # inputs to bf16 (XLA:CPU computes f32 either way)
+            return jnp.dot(h, layer["w"], precision=_HIGHEST) + layer["b"]
+
         def scorer(params, x):
             h = x
             for c in params["convs"]:
                 h = conv(h, c["w"], c["b"])
             h = h.reshape(h.shape[0], -1)
-            h = jax.nn.relu(h @ params["dense"]["w"] + params["dense"]["b"])
-            out = h @ params["head"]["w"] + params["head"]["b"]
+            out = dense(jax.nn.relu(dense(h, params["dense"])),
+                        params["head"])
             return jax.nn.sigmoid(out[:, 0]), jax.nn.softplus(out[:, 1])
 
         return scorer
@@ -267,7 +276,15 @@ class OperatorRuntime:
 
             def scorer(params, x):
                 self._record_trace(sig, tuple(x.shape), grouped=True)
-                return mapped(params, x)
+                if self.mesh is None:
+                    return mapped(params, x)
+                # XLA cannot partition a Pallas kernel itself, so each
+                # device runs the body on its own group members (or, for
+                # a replicated fallback shape, on all of them)
+                spec = P(shd.superbatch_spec(x.shape, self.mesh)[0])
+                return jax.shard_map(mapped, mesh=self.mesh, in_specs=spec,
+                                     out_specs=spec, check_vma=False)(
+                                         params, x)
 
             fn = jax.jit(scorer, donate_argnums=self._donate)
             self._super[sig] = fn

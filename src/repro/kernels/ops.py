@@ -2,7 +2,9 @@
 
 ``use_pallas(True)`` flips the model's hot paths onto the kernels (TPU);
 the default keeps the pure-jnp/XLA paths (CPU dry-run and tests compare
-both). Tests always call kernels with interpret=True.
+both). CPU tests run the kernels with interpret=True; on a TPU they
+are compiled (``tests/test_tpu_compile.py`` compiles them for a
+described v5e).
 
 ``conv_scorer_fn`` resolves the conv backend *once* and returns a
 callable with the choice baked in — callers that jit-compile (the

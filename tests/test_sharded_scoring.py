@@ -22,14 +22,7 @@ from repro.parallel import sharding
 ROOT = Path(__file__).resolve().parent.parent
 
 
-def _amesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-DATA4 = _amesh((4,), ("data",))
+DATA4 = AbstractMesh((4,), ("data",))
 
 
 # -- spec unit tests (no devices needed) -------------------------------------

@@ -11,17 +11,9 @@ from repro.configs.base import get_config
 from repro.models import layers, transformer as tf
 from repro.parallel import sharding
 
-def _amesh(sizes, names):
-    """AbstractMesh across jax versions: >=0.5 takes (sizes, names),
-    0.4.x takes a tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
 
-
-POD = _amesh((16, 16), ("data", "model"))
-MULTI = _amesh((2, 16, 16), ("pod", "data", "model"))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_default_rules_axes():
