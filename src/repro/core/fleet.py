@@ -69,6 +69,7 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Set
 
+from repro import obs
 from repro.core.counting import MaxCountExecutor, SampleCountExecutor
 from repro.core.filtering import TaggingExecutor
 from repro.core.query import Progress, QueryEnv
@@ -374,98 +375,99 @@ class FleetScheduler:
         exactly the pre-overlap barrier rounds', and every dispatch
         layout is bit-identical to single-demand scoring, so fleet runs
         stay bit-equivalent to standalone ones."""
-        if not self.tasks:
-            return {}
-        rt = self.runtime
-        calls0, frames0 = rt.calls, rt.frames_scored
-        batcher = ScoreBatcher(rt, group_max=self.group_max)
-        rounds = 0
-        # real host-time accounting (never feeds the simulated clocks):
-        # overlap_host_s integrates host work done while score
-        # dispatches were in flight on the device; result_block_s is
-        # time spent waiting on device results at the barrier
-        overlap_s = 0.0
-        block_s = 0.0
-        for task in self.tasks:
-            self._pot_add(task)
-        for task in self.tasks:
-            self._advance(task, None, batcher)
-            batcher.fire_complete(self._possible_sigs())
-        def event_key(t: _Task):
-            # earliest simulated event first; a verification orders
-            # *before* a transfer at the same instant — the inline call
-            # it replaces ran within the serving of the tick that
-            # produced it, i.e. before any tick at (or after) the
-            # verify's own simulated time, and a finished query's
-            # ``done_t == at`` tie in ``_active_at`` observes the
-            # difference
-            if t.vdemand is not None:
-                return (t.vdemand.at, 0, t.order)
-            return (t.tick.at, 1, t.order)
-
-        while True:
-            # earliest pending transfer/verification across the fleet
-            # first (global simulated-time order)
-            events = [t for t in self.tasks
-                      if t.tick is not None or t.vdemand is not None]
-            if events:
-                task = min(events, key=event_key)
-                t0 = time.perf_counter() if batcher.in_flight else None
-                if task.vdemand is not None:
-                    # the demand's simulated position is due: force its
-                    # slot through the service (it may already have
-                    # completed eagerly inside a full slot) and resume
-                    ticket, task.vticket = task.vticket, None
-                    self._advance(task, self.oracle.complete(ticket),
-                                  batcher)
-                else:
-                    item = task.tick
-                    task.ticks += 1
-                    self._advance(task, item.seconds *
-                                  self._uplink_factor(task, item.at),
-                                  batcher)
-                batcher.fire_complete(self._possible_sigs())
-                if t0 is not None:
-                    overlap_s += time.perf_counter() - t0
-                continue
-            # no transfers or verifications in flight (the no-ticks-
-            # pending watermark): flush partial groups, then resume
-            # every score-blocked stepper in task order from its
-            # on-device results
-            blocked = [t for t in self.tasks if t.demand is not None]
-            if not blocked:
-                break
-            rounds += 1
-            batcher.flush()
-            # every blocked task is about to be resumed and may submit
-            # again — back into the census (under its current
-            # signature) until its resumption decides otherwise
-            for task in blocked:
+        with obs.span(obs.FLEET_RUN):
+            if not self.tasks:
+                return {}
+            rt = self.runtime
+            calls0, frames0 = rt.calls, rt.frames_scored
+            batcher = ScoreBatcher(rt, group_max=self.group_max)
+            rounds = 0
+            # real host-time accounting (never feeds the simulated clocks):
+            # overlap_host_s integrates host work done while score
+            # dispatches were in flight on the device; result_block_s is
+            # time spent waiting on device results at the barrier
+            overlap_s = 0.0
+            block_s = 0.0
+            for task in self.tasks:
                 self._pot_add(task)
-            for task in blocked:
-                handle, task.handle = task.handle, None
-                t0 = time.perf_counter()
-                resp = handle.result()
-                block_s += time.perf_counter() - t0
-                t0 = time.perf_counter() if batcher.in_flight else None
-                self._advance(task, resp, batcher)
+            for task in self.tasks:
+                self._advance(task, None, batcher)
                 batcher.fire_complete(self._possible_sigs())
-                if t0 is not None:
-                    overlap_s += time.perf_counter() - t0
-        self.stats = {
-            "queries": len(self.tasks),
-            "cameras": len({t.camera for t in self.tasks}),
-            "score_rounds": rounds,
-            "dispatches": rt.calls - calls0,
-            "eager_dispatches": batcher.eager_dispatches,
-            "watermark_fires": dict(batcher.watermark_fires),
-            "frames_scored": rt.frames_scored - frames0,
-            "upload_ticks": sum(t.ticks for t in self.tasks),
-            "verify_demands": sum(t.verifies for t in self.tasks),
-            "overlap_host_s": round(overlap_s, 4),
-            "result_block_s": round(block_s, 4),
-            "oracle": self.oracle.stats() if self.oracle is not None
-            else None,
-            **rt.mesh_info(),
-        }
-        return {t.qid: t.result for t in self.tasks}
+            def event_key(t: _Task):
+                # earliest simulated event first; a verification orders
+                # *before* a transfer at the same instant — the inline call
+                # it replaces ran within the serving of the tick that
+                # produced it, i.e. before any tick at (or after) the
+                # verify's own simulated time, and a finished query's
+                # ``done_t == at`` tie in ``_active_at`` observes the
+                # difference
+                if t.vdemand is not None:
+                    return (t.vdemand.at, 0, t.order)
+                return (t.tick.at, 1, t.order)
+
+            while True:
+                # earliest pending transfer/verification across the fleet
+                # first (global simulated-time order)
+                events = [t for t in self.tasks
+                          if t.tick is not None or t.vdemand is not None]
+                if events:
+                    task = min(events, key=event_key)
+                    t0 = time.perf_counter() if batcher.in_flight else None
+                    if task.vdemand is not None:
+                        # the demand's simulated position is due: force its
+                        # slot through the service (it may already have
+                        # completed eagerly inside a full slot) and resume
+                        ticket, task.vticket = task.vticket, None
+                        self._advance(task, self.oracle.complete(ticket),
+                                      batcher)
+                    else:
+                        item = task.tick
+                        task.ticks += 1
+                        self._advance(task, item.seconds *
+                                      self._uplink_factor(task, item.at),
+                                      batcher)
+                    batcher.fire_complete(self._possible_sigs())
+                    if t0 is not None:
+                        overlap_s += time.perf_counter() - t0
+                    continue
+                # no transfers or verifications in flight (the no-ticks-
+                # pending watermark): flush partial groups, then resume
+                # every score-blocked stepper in task order from its
+                # on-device results
+                blocked = [t for t in self.tasks if t.demand is not None]
+                if not blocked:
+                    break
+                rounds += 1
+                batcher.flush()
+                # every blocked task is about to be resumed and may submit
+                # again — back into the census (under its current
+                # signature) until its resumption decides otherwise
+                for task in blocked:
+                    self._pot_add(task)
+                for task in blocked:
+                    handle, task.handle = task.handle, None
+                    t0 = time.perf_counter()
+                    resp = handle.result()
+                    block_s += time.perf_counter() - t0
+                    t0 = time.perf_counter() if batcher.in_flight else None
+                    self._advance(task, resp, batcher)
+                    batcher.fire_complete(self._possible_sigs())
+                    if t0 is not None:
+                        overlap_s += time.perf_counter() - t0
+            self.stats = {
+                "queries": len(self.tasks),
+                "cameras": len({t.camera for t in self.tasks}),
+                "score_rounds": rounds,
+                "dispatches": rt.calls - calls0,
+                "eager_dispatches": batcher.eager_dispatches,
+                "watermark_fires": dict(batcher.watermark_fires),
+                "frames_scored": rt.frames_scored - frames0,
+                "upload_ticks": sum(t.ticks for t in self.tasks),
+                "verify_demands": sum(t.verifies for t in self.tasks),
+                "overlap_host_s": round(overlap_s, 4),
+                "result_block_s": round(block_s, 4),
+                "oracle": self.oracle.stats() if self.oracle is not None
+                else None,
+                **rt.mesh_info(),
+            }
+            return {t.qid: t.result for t in self.tasks}

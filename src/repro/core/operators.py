@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops as _kops
 
 
@@ -173,13 +174,15 @@ def train_operator(arch: OperatorArch, params: Optional[dict], crops,
                    train_count: bool = True) -> dict:
     """Adam fine-tune on (crops, labels, counts); resumable (online
     training keeps improving the same operator as more samples arrive)."""
-    x = jnp.asarray(crops, jnp.float32)
-    yp = jnp.asarray(labels, jnp.float32)
-    yc = jnp.asarray(counts, jnp.float32)
-    if params is None:
-        params = init_operator(arch, jax.random.PRNGKey(seed))
-    m = jax.tree_util.tree_map(jnp.zeros_like, params)
-    v = jax.tree_util.tree_map(jnp.zeros_like, params)
+    with obs.span(obs.TRAIN_UPLOAD):
+        x = jnp.asarray(crops, jnp.float32)
+        yp = jnp.asarray(labels, jnp.float32)
+        yc = jnp.asarray(counts, jnp.float32)
+    with obs.span(obs.TRAIN_INIT):
+        if params is None:
+            params = init_operator(arch, jax.random.PRNGKey(seed))
+        m = jax.tree_util.tree_map(jnp.zeros_like, params)
+        v = jax.tree_util.tree_map(jnp.zeros_like, params)
     rng = np.random.default_rng(seed)
     n = x.shape[0]
     # wall-clock scaling for expensive ops (simulated time charged apart)
@@ -194,21 +197,25 @@ def train_operator(arch: OperatorArch, params: Optional[dict], crops,
     decay = np.float32(1 - lr * wd)
     lr32 = np.float32(lr)
     for t in range(1, steps + 1):
-        if balanced:
-            half = min(batch, n) // 2
-            sel = np.concatenate([
-                rng.choice(pos_idx, half, replace=True),
-                rng.choice(neg_idx, min(batch, n) - half, replace=True)])
-        else:
-            sel = rng.integers(0, n, size=min(batch, n))
-        # brightness augmentation: the scene dims over the day; operators
-        # must generalize across capture hours
-        bright = np.asarray(rng.uniform(0.7, 1.3, (len(sel), 1, 1, 1)),
-                            np.float32)
-        params, m, v = _adam_step()(
-            params, m, v, x[sel], bright, yp[sel], yc[sel],
-            np.float32(1 - 0.9 ** t), np.float32(1 - 0.999 ** t),
-            decay, lr32, train_count)
+        with obs.span(obs.TRAIN_STEP):
+            if balanced:
+                half = min(batch, n) // 2
+                sel = np.concatenate([
+                    rng.choice(pos_idx, half, replace=True),
+                    rng.choice(neg_idx, min(batch, n) - half, replace=True)])
+            else:
+                sel = rng.integers(0, n, size=min(batch, n))
+            # brightness augmentation: the scene dims over the day;
+            # operators must generalize across capture hours
+            bright = np.asarray(rng.uniform(0.7, 1.3, (len(sel), 1, 1, 1)),
+                                np.float32)
+            with obs.span(obs.TRAIN_GATHER):
+                xb, ypb, ycb = x[sel], yp[sel], yc[sel]
+            with obs.span(obs.TRAIN_DISPATCH):
+                params, m, v = _adam_step()(
+                    params, m, v, xb, bright, ypb, ycb,
+                    np.float32(1 - 0.9 ** t), np.float32(1 - 0.999 ** t),
+                    decay, lr32, train_count)
     return params
 
 

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.kernels import ops as kops
 from repro.parallel import sharding as shd
 
@@ -403,7 +404,8 @@ class OperatorRuntime:
         else:
             self.bucketed_calls += 1
         self._shape_vocab.setdefault(sig, set()).add(tuple(x.shape))
-        return fn(params, self._place(x, grouped=(kind == "super")))
+        with obs.span(obs.SCORE_DISPATCH):
+            return fn(params, self._place(x, grouped=(kind == "super")))
 
     def _dispatch_chunk(self, sig: ArchSig, params, x: np.ndarray):
         """One chunk through the lean or bucketed layer (padding as the
@@ -437,8 +439,9 @@ class OperatorRuntime:
             xb = x[i:i + self.chunk]
             m = xb.shape[0]
             p, c = self._dispatch_chunk(sig, params, xb)
-            probs[i:i + m] = np.asarray(p, np.float64)[:m]
-            counts[i:i + m] = np.asarray(c, np.float64)[:m]
+            with obs.span(obs.SCORE_WAIT):
+                probs[i:i + m] = np.asarray(p, np.float64)[:m]
+                counts[i:i + m] = np.asarray(c, np.float64)[:m]
         return probs, counts
 
     def score(self, trained, bank, idxs) -> Tuple[np.ndarray, np.ndarray]:
@@ -488,8 +491,9 @@ class _Out:
 
     def to_np(self) -> Tuple[np.ndarray, np.ndarray]:
         if self._np is None:
-            self._np = (np.asarray(self.p, np.float64),
-                        np.asarray(self.c, np.float64))
+            with obs.span(obs.SCORE_WAIT):
+                self._np = (np.asarray(self.p, np.float64),
+                            np.asarray(self.c, np.float64))
             self.p = self.c = None          # free the device buffers
             if self._cb is not None:
                 self._cb()
@@ -589,33 +593,34 @@ class ScoreBatcher:
     def submit(self, trained, bank, idxs) -> ScoreHandle:
         """Enqueue one demand; returns its handle (resolve after the
         batcher is flushed)."""
-        rt = self.rt
-        arch = trained.arch
-        sig = arch_signature(arch)
-        idxs = np.asarray(idxs, np.int64)
-        handle = ScoreHandle(len(idxs))
-        if len(idxs) == 0:
+        with obs.span(obs.SCORE_SUBMIT):
+            rt = self.rt
+            arch = trained.arch
+            sig = arch_signature(arch)
+            idxs = np.asarray(idxs, np.int64)
+            handle = ScoreHandle(len(idxs))
+            if len(idxs) == 0:
+                return handle
+            rt.frames_scored += len(idxs)
+            for i in range(0, len(idxs), rt.chunk):
+                sel = idxs[i:i + rt.chunk]
+                x = np.asarray(bank.crops(sel, arch.region, arch.input_size),
+                               np.float32)
+                m = x.shape[0]
+                handle._chunks += 1
+                if self.group_max == 1 or rt.is_small(sig, m):
+                    p, c = rt._dispatch_chunk(sig, trained.params, x)
+                    handle._add_part(i, m, self._out(p, c), None)
+                    continue
+                b = rt._bucket(m)
+                q = self._queues.setdefault((sig, b), [])
+                q.append((handle, i, m, trained.params, rt._pad_rows(x, b)))
+                if len(q) >= self.group_max:
+                    self._dispatch_group(sig, q)
+                    self._queues[(sig, b)] = []
+                    self.eager_dispatches += 1
+                    self.watermark_fires["group_max"] += 1
             return handle
-        rt.frames_scored += len(idxs)
-        for i in range(0, len(idxs), rt.chunk):
-            sel = idxs[i:i + rt.chunk]
-            x = np.asarray(bank.crops(sel, arch.region, arch.input_size),
-                           np.float32)
-            m = x.shape[0]
-            handle._chunks += 1
-            if self.group_max == 1 or rt.is_small(sig, m):
-                p, c = rt._dispatch_chunk(sig, trained.params, x)
-                handle._add_part(i, m, self._out(p, c), None)
-                continue
-            b = rt._bucket(m)
-            q = self._queues.setdefault((sig, b), [])
-            q.append((handle, i, m, trained.params, rt._pad_rows(x, b)))
-            if len(q) >= self.group_max:
-                self._dispatch_group(sig, q)
-                self._queues[(sig, b)] = []
-                self.eager_dispatches += 1
-                self.watermark_fires["group_max"] += 1
-        return handle
 
     def fire_complete(self, possible_sigs: Optional[Set[ArchSig]]) -> None:
         """The bucket-complete watermark: dispatch every queue whose
@@ -649,9 +654,10 @@ class ScoreBatcher:
                                 kind="bucketed")
             handle._add_part(off, m, self._out(p, c), None)
             return
-        stacked = jax.tree_util.tree_map(
-            lambda *leaves: jnp.stack(leaves), *[g[3] for g in group])
-        xs = np.stack([g[4] for g in group])
+        with obs.span(obs.SCORE_STACK):
+            stacked = jax.tree_util.tree_map(
+                lambda *leaves: jnp.stack(leaves), *[g[3] for g in group])
+            xs = np.stack([g[4] for g in group])
         ps, cs = rt._dispatch(sig, rt._super_fn(sig), stacked, xs,
                               kind="super")
         out = self._out(ps, cs)

@@ -15,6 +15,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import operators as ops_mod
 from repro.core import runtime as rt_mod
 from repro.core.hardware import CloudModel
@@ -35,27 +36,30 @@ class FrameBank:
         idxs = [int(i) for i in idxs]
         missing = [i for i in idxs if i not in self._frames]
         if missing:
-            rendered = self.video.render_frames(missing)
-            for i, f in zip(missing, rendered):
-                if len(self._frames) >= self.max_frames:
-                    self._frames.pop(next(iter(self._frames)))
-                self._frames[i] = (f * 255).astype(np.uint8)
+            with obs.span(obs.FRAMES_RENDER):
+                rendered = self.video.render_frames(missing)
+                for i, f in zip(missing, rendered):
+                    if len(self._frames) >= self.max_frames:
+                        self._frames.pop(next(iter(self._frames)))
+                    self._frames[i] = (f * 255).astype(np.uint8)
         return np.stack([self._frames[i] for i in idxs]).astype(np.float32) / 255.0
 
     def crops(self, idxs, region: Optional[Tuple[int, int, int, int]],
               size: int) -> np.ndarray:
-        key = (region, size)
-        cache = self._crop_cache.setdefault(key, {})
-        idxs = [int(i) for i in idxs]
-        missing = [i for i in idxs if i not in cache]
-        if missing:
-            frames = self.frames(missing)
-            y0, x0, y1, x1 = region if region else (0, 0, FRAME_H, FRAME_W)
-            crop = frames[:, int(y0):int(y1), int(x0):int(x1), :]
-            resized = _resize_batch(crop, size)
-            for i, c in zip(missing, resized):
-                cache[i] = (c * 255).astype(np.uint8)
-        return np.stack([cache[i] for i in idxs]).astype(np.float32) / 255.0
+        with obs.span(obs.FRAMES_CROP):
+            key = (region, size)
+            cache = self._crop_cache.setdefault(key, {})
+            idxs = [int(i) for i in idxs]
+            missing = [i for i in idxs if i not in cache]
+            if missing:
+                frames = self.frames(missing)
+                y0, x0, y1, x1 = region or (0, 0, FRAME_H, FRAME_W)
+                crop = frames[:, int(y0):int(y1), int(x0):int(x1), :]
+                resized = _resize_batch(crop, size)
+                for i, c in zip(missing, resized):
+                    cache[i] = (c * 255).astype(np.uint8)
+            return np.stack([cache[i] for i in idxs]).astype(
+                np.float32) / 255.0
 
 
 @dataclass
@@ -112,34 +116,38 @@ class CloudTrainer:
     def train(self, arch: OperatorArch, max_samples: int = 4000) -> TrainedOp:
         """(Re)train ``arch`` on the current pool; returns TrainedOp with
         validation metrics and calibrated thresholds."""
-        ti, tl, tc, vi, vl, vc = self._splits()
-        if len(ti) > max_samples:
-            sel = np.random.default_rng(self.seed).choice(
-                len(ti), max_samples, replace=False)
-            ti, tl, tc = ti[sel], tl[sel], tc[sel]
-        prev = self._trained.get(arch.name)
-        params = prev.params if prev else None
-        crops = self.bank.crops(ti, arch.region, arch.input_size)
-        # scale step count down for expensive ops (wall-clock budget on the
-        # host; simulated training time is charged separately)
-        steps = int(np.clip(self.train_steps * 8e7 / max(arch.flops, 1),
-                            40, self.train_steps))
-        params = ops_mod.train_operator(
-            arch, params, crops, tl, tc, steps=steps, seed=self.seed)
-        # validate (batched through the shared OperatorRuntime jit cache)
-        if len(vi):
-            vcrops = self.bank.crops(vi, arch.region, arch.input_size)
-            vs, vcnt = rt_mod.get_runtime().score_crops(params, arch, vcrops)
-            auc = _auc(vs, vl > 0.5)
-            lo, hi = ops_mod.calibrate_thresholds(vs, vl > 0.5,
-                                                  self.error_budget)
-            gamma = ops_mod.gamma_of(vs, lo, hi)
-            mae = float(np.mean(np.abs(vcnt - vc))) if len(vc) else 1.0
-        else:
-            auc, lo, hi, gamma, mae = 0.5, 0.0, 1.0, 0.0, 1.0
-        top = TrainedOp(arch, params, len(ti), auc, (lo, hi), gamma, mae)
-        self._trained[arch.name] = top
-        return top
+        with obs.span(obs.TRAIN):
+            ti, tl, tc, vi, vl, vc = self._splits()
+            if len(ti) > max_samples:
+                sel = np.random.default_rng(self.seed).choice(
+                    len(ti), max_samples, replace=False)
+                ti, tl, tc = ti[sel], tl[sel], tc[sel]
+            prev = self._trained.get(arch.name)
+            params = prev.params if prev else None
+            crops = self.bank.crops(ti, arch.region, arch.input_size)
+            # scale step count down for expensive ops (wall-clock budget on
+            # the host; simulated training time is charged separately)
+            steps = int(np.clip(self.train_steps * 8e7 / max(arch.flops, 1),
+                                40, self.train_steps))
+            params = ops_mod.train_operator(
+                arch, params, crops, tl, tc, steps=steps, seed=self.seed)
+            with obs.span(obs.TRAIN_VALIDATE):
+                auc, lo, hi, gamma, mae = self._validate(arch, params,
+                                                         vi, vl, vc)
+            top = TrainedOp(arch, params, len(ti), auc, (lo, hi), gamma, mae)
+            self._trained[arch.name] = top
+            return top
+
+    def _validate(self, arch: OperatorArch, params: dict, vi, vl, vc):
+        """(AUC, lo, hi, gamma, count MAE) on the validation split, scored
+        through the shared OperatorRuntime jit cache."""
+        if not len(vi):
+            return 0.5, 0.0, 1.0, 0.0, 1.0
+        vcrops = self.bank.crops(vi, arch.region, arch.input_size)
+        vs, vcnt = rt_mod.get_runtime().score_crops(params, arch, vcrops)
+        lo, hi = ops_mod.calibrate_thresholds(vs, vl > 0.5, self.error_budget)
+        mae = float(np.mean(np.abs(vcnt - vc))) if len(vc) else 1.0
+        return _auc(vs, vl > 0.5), lo, hi, ops_mod.gamma_of(vs, lo, hi), mae
 
     def get(self, name: str) -> Optional[TrainedOp]:
         return self._trained.get(name)

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core import landmarks as lm_mod
 from repro.core.fleet import FleetScheduler, make_executor
 from repro.core.query import Progress, Query, make_env
@@ -85,9 +86,10 @@ class FleetService:
         if qid in self._progress:
             raise ValueError(f"duplicate qid: {qid!r}")
         video, store, bank = self._cameras[camera]
-        env = make_env(video, query, store, net=net, bank=bank,
-                       train_steps=self.train_steps)
-        executor = make_executor(env, full_family=self.full_family)
+        with obs.span(obs.FLEET_SUBMIT):
+            env = make_env(video, query, store, net=net, bank=bank,
+                           train_steps=self.train_steps)
+            executor = make_executor(env, full_family=self.full_family)
         self._n_submitted += 1
         self._progress[qid] = Progress()
         step_kwargs.update(priority=priority, weight=weight, slo_s=slo_s)

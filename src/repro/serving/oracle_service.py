@@ -53,6 +53,7 @@ import heapq
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core import oracle
 from repro.core.stepper import VerifyDemand
 
@@ -166,24 +167,25 @@ class OracleService:
         stamped (the routing driver knows the query's identity; steppers
         do not).  An unregistered qid opens a default lane — ``env`` is
         required then (it is the answer source)."""
-        qid = demand.qid if demand.qid is not None else "?"
-        lane = self.lanes.get(qid)
-        if lane is None:
-            if env is None:
-                raise ValueError(
-                    f"qid {qid!r} not registered and no env given")
-            lane = self.register(qid, env, priority=demand.priority)
-        # WFQ: this demand finishes one weighted unit after the later of
-        # the lane's previous finish and the current virtual clock
-        lane.vft = max(self._vclock, lane.vft) + 1.0 / lane.weight
-        ticket = VerifyTicket(demand, lane, self._seq, lane.vft,
-                              self.slots_run)
-        self._seq += 1
-        heapq.heappush(self._heap, (self._key(ticket), ticket.seq, ticket))
-        if self.eager:
-            while self.pending >= self.slot_frames:
-                self.step()
-        return ticket
+        with obs.span(obs.VERIFY):
+            qid = demand.qid if demand.qid is not None else "?"
+            lane = self.lanes.get(qid)
+            if lane is None:
+                if env is None:
+                    raise ValueError(
+                        f"qid {qid!r} not registered and no env given")
+                lane = self.register(qid, env, priority=demand.priority)
+            # WFQ: this demand finishes one weighted unit after the later of
+            # the lane's previous finish and the current virtual clock
+            lane.vft = max(self._vclock, lane.vft) + 1.0 / lane.weight
+            ticket = VerifyTicket(demand, lane, self._seq, lane.vft,
+                                  self.slots_run)
+            self._seq += 1
+            heapq.heappush(self._heap, (self._key(ticket), ticket.seq, ticket))
+            if self.eager:
+                while self.pending >= self.slot_frames:
+                    self.step()
+            return ticket
 
     def _key(self, t: VerifyTicket) -> tuple:
         """Admission order: overdue first, then priority (higher first),
@@ -202,31 +204,32 @@ class OracleService:
         """Run one verification slot: admit up to ``slot_frames``
         pending demands (admission order), verify them in one vectorized
         pass, advance the simulated clock, resolve their tickets."""
-        if not self._heap:
-            return []
-        # overdue-ness depends on self.now, which moves between slots:
-        # re-key the frontier so expired SLOs actually preempt
-        self._rekey_overdue()
-        batch: List[VerifyTicket] = []
-        while self._heap and len(batch) < self.slot_frames:
-            _, _, ticket = heapq.heappop(self._heap)
-            batch.append(ticket)
-        self._verify_slot(batch)
-        self.slots_run += 1
-        self._occupancy.append(len(batch))
-        self._vclock = max(self._vclock, min(t.vft for t in batch))
-        start = max(self.now, min(t.demand.at for t in batch))
-        finish = start + len(batch) / self.det_fps
-        self.now = finish
-        for t in batch:
-            t.done = True
-            t.finish_t = finish
-            t.lane.served += 1
-            t.lane.delays.append(max(0.0, finish - t.demand.at))
-            t.lane.max_slots_waited = max(
-                t.lane.max_slots_waited, self.slots_run - t.submit_slot)
-        self.frames_verified += len(batch)
-        return batch
+        with obs.span(obs.VERIFY):
+            if not self._heap:
+                return []
+            # overdue-ness depends on self.now, which moves between slots:
+            # re-key the frontier so expired SLOs actually preempt
+            self._rekey_overdue()
+            batch: List[VerifyTicket] = []
+            while self._heap and len(batch) < self.slot_frames:
+                _, _, ticket = heapq.heappop(self._heap)
+                batch.append(ticket)
+            self._verify_slot(batch)
+            self.slots_run += 1
+            self._occupancy.append(len(batch))
+            self._vclock = max(self._vclock, min(t.vft for t in batch))
+            start = max(self.now, min(t.demand.at for t in batch))
+            finish = start + len(batch) / self.det_fps
+            self.now = finish
+            for t in batch:
+                t.done = True
+                t.finish_t = finish
+                t.lane.served += 1
+                t.lane.delays.append(max(0.0, finish - t.demand.at))
+                t.lane.max_slots_waited = max(
+                    t.lane.max_slots_waited, self.slots_run - t.submit_slot)
+            self.frames_verified += len(batch)
+            return batch
 
     def _rekey_overdue(self) -> None:
         """Rebuild heap keys when SLO expiry changed any ordering class
@@ -245,14 +248,16 @@ class OracleService:
         """Drive slots (admission order) until ``ticket`` resolves —
         the routing driver calls this when the demand's simulated-time
         position is reached and the answer is needed *now*."""
-        while not ticket.done:
-            self.step()
-        return ticket.result()
+        with obs.span(obs.VERIFY):
+            while not ticket.done:
+                self.step()
+            return ticket.result()
 
     def flush(self) -> None:
         """Drain every pending demand (end-of-run barrier)."""
-        while self._heap:
-            self.step()
+        with obs.span(obs.VERIFY):
+            while self._heap:
+                self.step()
 
     # -- verification --------------------------------------------------------
 
