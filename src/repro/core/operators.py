@@ -168,54 +168,86 @@ def _adam_step():
                    donate_argnums=donate)
 
 
-def train_operator(arch: OperatorArch, params: Optional[dict], crops,
-                   labels, counts, *, steps: int = 120, batch: int = 128,
-                   lr: float = 2e-3, seed: int = 0,
-                   train_count: bool = True) -> dict:
-    """Adam fine-tune on (crops, labels, counts); resumable (online
-    training keeps improving the same operator as more samples arrive)."""
-    with obs.span(obs.TRAIN_UPLOAD):
-        x = jnp.asarray(crops, jnp.float32)
-        yp = jnp.asarray(labels, jnp.float32)
-        yc = jnp.asarray(counts, jnp.float32)
-    with obs.span(obs.TRAIN_INIT):
-        if params is None:
-            params = init_operator(arch, jax.random.PRNGKey(seed))
-        m = jax.tree_util.tree_map(jnp.zeros_like, params)
-        v = jax.tree_util.tree_map(jnp.zeros_like, params)
-    rng = np.random.default_rng(seed)
-    n = x.shape[0]
-    # wall-clock scaling for expensive ops (simulated time charged apart)
-    batch = int(np.clip(batch * 8e7 / max(arch.flops, 1), 32, batch))
+def _draw_minibatches(rng, labels, batch: int, steps: int):
+    """Every step's minibatch indices ``(steps, b)`` and brightness
+    ``(steps, b, 1, 1, 1)``, ``b = min(batch, n)`` for ``n`` samples
+    (one label each). Drawn from one
+    stream, step after step (the positives, then the negatives, or
+    plain indices when a class is missing; then the brightness), so a
+    seed trains to the same parameters however the steps are
+    dispatched: do not vectorise across steps."""
+    n = len(labels)
+    b = min(batch, n)
     # balanced minibatches: surveillance positives are rare (<10%); plain
     # sampling collapses the scorer to "always negative"
     lab = np.asarray(labels) > 0.5
     pos_idx = np.nonzero(lab)[0]
     neg_idx = np.nonzero(~lab)[0]
     balanced = len(pos_idx) > 0 and len(neg_idx) > 0
+    idx = np.empty((steps, b), np.int32)
+    bright = np.empty((steps, b, 1, 1, 1), np.float32)
+    for t in range(steps):
+        if balanced:
+            half = b // 2
+            idx[t, :half] = rng.choice(pos_idx, half, replace=True)
+            idx[t, half:] = rng.choice(neg_idx, b - half, replace=True)
+        else:
+            idx[t] = rng.integers(0, n, size=b)
+        # brightness augmentation: the scene dims over the day;
+        # operators must generalize across capture hours
+        bright[t] = rng.uniform(0.7, 1.3, (b, 1, 1, 1))
+    return idx, bright
+
+
+@jax.jit
+def _gather_step(x, yp, yc, idx, bright, sched, t):
+    """Step ``t``'s inputs from the call's uploaded draws, in one
+    dispatch: its crops, presence, counts and brightness, and its bias
+    corrections (bc1, bc2). Nothing is donated; the inputs serve every
+    step of the call."""
+    sel = idx[t]
+    return x[sel], yp[sel], yc[sel], bright[t], sched[t, 0], sched[t, 1]
+
+
+def train_operator(arch: OperatorArch, params: Optional[dict], crops,
+                   labels, counts, *, steps: int = 120, batch: int = 128,
+                   lr: float = 2e-3, seed: int = 0,
+                   train_count: bool = True) -> dict:
+    """Adam fine-tune on (crops, labels, counts); resumable (online
+    training keeps improving the same operator as more samples arrive).
+
+    Every step's draws and schedule terms go to the device once, before
+    the first step; a step is then two dispatches, its gather and
+    ``_adam_step``, whose only host input is the step's number."""
+    # wall-clock scaling for expensive ops (simulated time charged apart)
+    batch = int(np.clip(batch * 8e7 / max(arch.flops, 1), 32, batch))
+    idx, bright = _draw_minibatches(np.random.default_rng(seed), labels,
+                                    batch, steps)
+    # Adam's bias corrections, computed in float64 and passed as f32
+    sched = np.array([(1 - 0.9 ** t, 1 - 0.999 ** t)
+                      for t in range(1, steps + 1)], np.float32)
     wd = 1e-4
-    decay = np.float32(1 - lr * wd)
-    lr32 = np.float32(lr)
-    for t in range(1, steps + 1):
+    with obs.span(obs.TRAIN_UPLOAD):
+        x = jnp.asarray(crops, jnp.float32)
+        yp = jnp.asarray(labels, jnp.float32)
+        yc = jnp.asarray(counts, jnp.float32)
+        idx, bright, sched = map(jnp.asarray, (idx, bright, sched))
+        decay = jnp.asarray(np.float32(1 - lr * wd))
+        lr32 = jnp.asarray(np.float32(lr))
+    with obs.span(obs.TRAIN_INIT):
+        if params is None:
+            params = init_operator(arch, jax.random.PRNGKey(seed))
+        m = jax.tree_util.tree_map(jnp.zeros_like, params)
+        v = jax.tree_util.tree_map(jnp.zeros_like, params)
+    for t in range(steps):
         with obs.span(obs.TRAIN_STEP):
-            if balanced:
-                half = min(batch, n) // 2
-                sel = np.concatenate([
-                    rng.choice(pos_idx, half, replace=True),
-                    rng.choice(neg_idx, min(batch, n) - half, replace=True)])
-            else:
-                sel = rng.integers(0, n, size=min(batch, n))
-            # brightness augmentation: the scene dims over the day;
-            # operators must generalize across capture hours
-            bright = np.asarray(rng.uniform(0.7, 1.3, (len(sel), 1, 1, 1)),
-                                np.float32)
             with obs.span(obs.TRAIN_GATHER):
-                xb, ypb, ycb = x[sel], yp[sel], yc[sel]
+                xb, ypb, ycb, bright_t, bc1, bc2 = _gather_step(
+                    x, yp, yc, idx, bright, sched, np.int32(t))
             with obs.span(obs.TRAIN_DISPATCH):
                 params, m, v = _adam_step()(
-                    params, m, v, xb, bright, ypb, ycb,
-                    np.float32(1 - 0.9 ** t), np.float32(1 - 0.999 ** t),
-                    decay, lr32, train_count)
+                    params, m, v, xb, bright_t, ypb, ycb, bc1, bc2, decay,
+                    lr32, train_count)
     return params
 
 

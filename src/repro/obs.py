@@ -14,9 +14,10 @@ from jax.profiler import TraceAnnotation
 # operator training (core/training.CloudTrainer, core/operators)
 TRAIN = "diva.train"                    # one CloudTrainer.train call
 TRAIN_INIT = "diva.train.init"          # init_operator, zeroed Adam state
-TRAIN_UPLOAD = "diva.train.upload"      # crops, labels, counts to device
+TRAIN_UPLOAD = "diva.train.upload"      # crops, labels, counts, every step's
+                                        # draws and schedule terms to device
 TRAIN_STEP = "diva.train.step"          # one Adam iteration, all of it
-TRAIN_GATHER = "diva.train.gather"      # x[sel], yp[sel], yc[sel]
+TRAIN_GATHER = "diva.train.gather"      # the jitted _gather_step call
 TRAIN_DISPATCH = "diva.train.dispatch"  # the jitted _adam_step call
 TRAIN_VALIDATE = "diva.train.validate"  # validation crops, scores, AUC
 

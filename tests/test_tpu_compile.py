@@ -123,3 +123,16 @@ def test_adam_step_compiles(one_chip):
         _spec((batch,), one_chip), scalar, scalar, scalar, scalar,
         True).compile()
     _fits(compiled)
+
+
+def test_gather_step_compiles(one_chip):
+    """A step's minibatch gather at the widest operator, the batch and
+    steps ``train_operator`` runs it with (128, 150) and a training set
+    at ``CloudTrainer.train``'s ``max_samples`` (4,000 crops)."""
+    n, steps, batch = 4000, 150, 128
+    compiled = operators._gather_step.lower(
+        _spec((n, 100, 100, 3), one_chip), _spec((n,), one_chip),
+        _spec((n,), one_chip), _spec((steps, batch), one_chip, jnp.int32),
+        _spec((steps, batch, 1, 1, 1), one_chip), _spec((steps, 2), one_chip),
+        _spec((), one_chip, jnp.int32)).compile()
+    _fits(compiled)
