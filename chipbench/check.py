@@ -22,6 +22,15 @@ Five numbers, each with the cell's limit (``limits/<cell>.json``):
     every positive frame, a tagging that left a frame untagged, a
     count whose final value is not the one its verified frames give.
 
+A detector reference module adds the numbers its ``numbers`` returns.
+
+Every detector answer comes through ``answer``: from the module
+``detectors/<reference>.py`` where the configuration's entry of that
+detector names one, else from the seeded tiers of ``reference.Scene``.
+A frame the reference is not ``sure`` of (its score within the module's
+margin of its threshold, where rounding can flip it) is not held
+against the program: there the reference keeps the program's answer.
+
 With ``control`` the reference in the next precision below the
 configuration's (``CONTROL``) stands in the program's place for the
 scoring and training numbers, and goes through the same comparison.
@@ -31,7 +40,11 @@ operations (the Adam update leaf by leaf, the initial weights) cost
 milliseconds, where on a TPU each one is a program of its own, to
 compile or fetch from the cache, and the check outlasted the run's
 time limit. Its precisions are explicit casts, so they read the same
-on either backend.
+on either backend. A detector reference module decides where its own
+reference runs: a network over every frame of a queried range is too
+slow for the CPU, and may put its arrays on the TPU explicitly and run
+jitted at f32 ``Precision.HIGHEST`` (the check runs after the window
+has closed and the device's peak memory has been read).
 """
 from __future__ import annotations
 
@@ -42,6 +55,7 @@ import jax
 import numpy as np
 
 import reference as ref
+import traffic
 from probes import STEPS
 
 CONTROL = {"highest": "high", "high": "bf16", "bf16": "fp8"}
@@ -101,6 +115,27 @@ def scoring(demands, scenes, control: Optional[str]
     return {"score_prob_gap": gap}
 
 
+def answer(scene, idxs, cls: str, det: dict):
+    """The reference detector ``det``'s answers in frames ``idxs`` of
+    ``scene``: presence (bool), count (int) and ``sure`` (bool), one
+    each per frame, as numpy arrays.
+
+    A detector whose entry names a ``reference`` answers through that
+    module's ``answer(scene, idxs, cls, det)``, which returns the same
+    three; ``sure`` is False where its score lies within the module's
+    stated margin of its threshold. The others are the seeded tiers of
+    ``reference.Scene``, sure of every frame."""
+    mod = det.get("module")
+    if mod is not None:
+        pos, cnt, sure = mod.answer(scene, idxs, cls, det)
+    else:
+        a = [scene.answer(int(i), cls, det) for i in idxs]
+        pos, cnt = [p for p, _ in a], [c for _, c in a]
+        sure = np.ones(len(a), bool)
+    return (np.asarray(pos, bool), np.asarray(cnt, np.int64),
+            np.asarray(sure, bool))
+
+
 def norm_gap(g: Dict[str, float], r: Dict[str, float]) -> float:
     """Worst leaf: |program's norm - reference's| over the larger of the
     reference leaf's norm and the median leaf's."""
@@ -108,40 +143,43 @@ def norm_gap(g: Dict[str, float], r: Dict[str, float]) -> float:
     return max(abs(g[k] - r[k]) / max(r[k], floor, 1e-30) for k in r)
 
 
-def _labels(cap, scene, cfg, answers_log, task) -> Dict[int, tuple]:
-    """``{frame: (label, count)}`` the reference gives the frames of a
-    training set: the cloud detector's answer where the query had the
-    frame verified before the call, else the camera's landmark answer,
-    else the label flow tracking carries (retrieval only)."""
-    det = dict(name=cfg["cloud_detector"],
-               **cfg["detectors"][cfg["cloud_detector"]])
-    lm_det = dict(name=cfg["landmark_detector"],
-                  **cfg["detectors"][cfg["landmark_detector"]])
+def _labels(cap, scene, cfg, dets, answers_log, task) -> Dict[int, tuple]:
+    """``{frame: (label, count, sure)}`` the reference gives the frames
+    of a training set: the cloud detector's answer where the query had
+    the frame verified before the call, else the camera's landmark
+    answer, else the label flow tracking carries from a landmark
+    (retrieval only), as sure as that landmark's answer."""
     cls = task.env.query.cls
+    every = cfg["landmark_interval"]
     verified = {idx for rnd, qid, idx, *_ in answers_log[:cap["n_answers"]]
                 if rnd == cap["round"] and qid == task.qid}
-    flow = scene.flow_labels(cls, lm_det, cfg["landmark_interval"]) \
-        if task.env.query.kind == "retrieval" else {}
-    out = {}
-    for i in cap["idxs"].tolist():
-        if i in verified:
-            pos, cnt = scene.answer(i, cls, det)
-        elif i % cfg["landmark_interval"] == 0:
-            pos, cnt = scene.answer(i, cls, lm_det)
-        elif i in flow:
-            out[i] = flow[i]
-            continue
-        else:
-            continue                       # no label the reference knows
-        out[i] = (1.0 if pos else 0.0, float(cnt))
-    return out
+    idxs = cap["idxs"].tolist()
+    retrieval = task.env.query.kind == "retrieval"
+    # flow labels need every landmark of the archive
+    lm_frames = list(range(0, scene.n_frames, every)) if retrieval else \
+        [i for i in idxs if i % every == 0]
+    labels = {}
+    for frames, det in ((lm_frames, dets[cfg["landmark_detector"]]),
+                        ([i for i in idxs if i in verified],
+                         dets[cfg["cloud_detector"]])):
+        pos, cnt, sure = answer(scene, frames, cls, det)
+        labels.update((i, (float(p), float(c), bool(s)))
+                      for i, p, c, s in zip(frames, pos, cnt, sure))
+    if retrieval:
+        lms = {i: labels[i] for i in lm_frames}
+        flow = scene.flow_labels({i: (v[0] > 0, int(v[1]))
+                                  for i, v in lms.items()})
+        for i, (label, cnt, li) in flow.items():
+            labels.setdefault(i, (label, cnt, lms[li][2]))
+    return {i: labels[i] for i in idxs if i in labels}
 
 
 def _batches(cap, scene, labels):
     """The reference's own minibatches of the captured steps: each row
     of the program's minibatch is matched to the frame of the training
     set whose reference crop it is, and that frame's crop and labels
-    are used. None where a row matches no frame."""
+    are used; where the reference is not sure of a frame's label, the
+    row keeps the program's. None where a row matches no frame."""
     crops = scene.crops(cap["idxs"], cap["region"], cap["size"])
     row_of = {c.tobytes(): i for i, c in zip(cap["idxs"].tolist(), crops)}
     crop_of = dict(zip(cap["idxs"].tolist(), crops))
@@ -151,9 +189,13 @@ def _batches(cap, scene, labels):
         frames = [row_of.get(r.tobytes()) for r in xb]
         if any(f is None or f not in labels for f in frames):
             return None
+        lab = np.array([labels[f] for f in frames], np.float64)
+        sure = lab[:, 2] > 0
         out.append((np.stack([crop_of[f] for f in frames]), st["bright"],
-                    np.array([labels[f][0] for f in frames], np.float32),
-                    np.array([labels[f][1] for f in frames], np.float32)))
+                    np.where(sure, lab[:, 0], jax.device_get(st["yp"]))
+                    .astype(np.float32),
+                    np.where(sure, lab[:, 1], jax.device_get(st["yc"]))
+                    .astype(np.float32)))
     return out
 
 
@@ -193,7 +235,7 @@ def _trajectory_gaps(prog, ref_run, p0, batches, train_count) -> dict:
             "train_loss_gap": num / max(den, 1e-30)}
 
 
-def training(captures, scenes, cfg, answers_log, tasks,
+def training(captures, scenes, cfg, dets, answers_log, tasks,
              control: Optional[str]) -> Dict[str, Optional[float]]:
     """The training numbers over the captured calls (worst call). With
     ``control``, the reference at that precision stands in the
@@ -205,7 +247,7 @@ def training(captures, scenes, cfg, answers_log, tasks,
         batches = None
         if task is not None and cap["idxs"] is not None and \
                 len(cap["steps"]) == STEPS:
-            batches = _batches(cap, scene, _labels(cap, scene, cfg,
+            batches = _batches(cap, scene, _labels(cap, scene, cfg, dets,
                                                    answers_log, task))
         if batches is None:
             # a minibatch that is not the training set's frames
@@ -229,24 +271,22 @@ def training(captures, scenes, cfg, answers_log, tasks,
     return worst
 
 
-def answers(rounds, scenes, cfg, answers_log) -> List[str]:
+def answers(rounds, scenes, cfg, dets, answers_log) -> List[str]:
     """``"round/qid: reason"`` of every query whose answers disagree
     with the reference world."""
-    det = dict(name=cfg["cloud_detector"],
-               **cfg["detectors"][cfg["cloud_detector"]])
-    lm_det = dict(name=cfg["landmark_detector"],
-                  **cfg["detectors"][cfg["landmark_detector"]])
     nf = int(cfg["hours"] * 3600 * cfg["fps"])
     frames = np.arange(nf)
     lm_idxs = np.arange(0, nf, cfg["landmark_interval"])
     truth = {}
 
-    def gt(cam, cls):
-        if (cam, cls) not in truth:
-            a = [scenes[cam].answer(i, cls, det) for i in frames]
-            truth[cam, cls] = (np.array([x[0] for x in a]),
-                               np.array([x[1] for x in a]))
-        return truth[cam, cls]
+    def gt(cam, cls, of):
+        """The cloud detector's answers over the archive, or the
+        landmark detector's over the landmarks (``of``)."""
+        if (cam, cls, of) not in truth:
+            idxs, det = (frames, cfg["cloud_detector"]) if of == "cloud" \
+                else (lm_idxs, cfg["landmark_detector"])
+            truth[cam, cls, of] = answer(scenes[cam], idxs, cls, dets[det])
+        return truth[cam, cls, of]
 
     by_query: Dict[tuple, list] = {}
     for rnd, qid, idx, cls, pos, cnt in answers_log:
@@ -256,38 +296,47 @@ def answers(rounds, scenes, cfg, answers_log) -> List[str]:
         for task in r["tasks"]:
             kind, cls = task.env.query.kind, task.env.query.cls
             why = _query_fault(kind, cls, task, by_query.get(
-                (rnd, task.qid), []), gt(task.camera, cls),
-                [scenes[task.camera].answer(i, cls, lm_det)[1]
-                 for i in lm_idxs])
+                (rnd, task.qid), []), gt(task.camera, cls, "cloud"),
+                gt(task.camera, cls, "landmark"), lm_idxs)
             if why:
                 wrong.append(f"{rnd}/{task.qid}: {why}")
     return wrong
 
 
-def _query_fault(kind, cls, task, got, truth, lm_counts) -> str:
-    """Why one query's answers are wrong, or "" where they are right."""
-    pos_gt, cnt_gt = truth
+def _query_fault(kind, cls, task, got, truth, lm_truth, lm_idxs) -> str:
+    """Why one query's answers are wrong, or "" where they are right.
+    A frame the reference is not sure of is not held against the
+    query: its answer there is not compared, and a count takes the
+    program's own count of it."""
+    pos_gt, cnt_gt, sure = truth
     prog = task.result
     if prog is None or prog.done_t is None or not math.isfinite(prog.done_t):
         return "no final answer"
     for i, c, pos, cnt in got:
-        if c != cls or pos != bool(pos_gt[i]) or cnt != int(cnt_gt[i]):
+        if c != cls or sure[i] and (pos != bool(pos_gt[i])
+                                    or cnt != int(cnt_gt[i])):
             return f"frame {i} verified as ({pos}, {cnt}), detector says " \
                 f"({bool(pos_gt[i])}, {int(cnt_gt[i])})"
     final = prog.points[-1][1] if prog.points else None
     if kind == "retrieval":
-        found = {i for i, _, pos, _ in got if pos}
-        missed = set(np.nonzero(pos_gt)[0].tolist()) - found
-        if missed or len(found) != int(pos_gt.sum()):
+        found = {i for i, _, pos, _ in got if pos and sure[i]}
+        missed = set(np.nonzero(pos_gt & sure)[0].tolist()) - found
+        if missed or len(found) != int((pos_gt & sure).sum()):
             return f"retrieval missed {len(missed)} positive frames"
     elif kind == "tagging":
         tags = task.executor.tags
         if np.any(tags == 0):
             return f"{int(np.sum(tags == 0))} frames untagged"
-        cloud = np.nonzero(tags >= 3)[0]
+        cloud = np.nonzero((tags >= 3) & sure)[0]
         if np.any((tags[cloud] == 4) != pos_gt[cloud]):
             return "a cloud tag disagrees with the detector"
     elif kind.startswith("count_"):
+        cnt_gt = np.where(sure, cnt_gt, task.env.gt_count)
+        _, lm_cnt, lm_sure = lm_truth
+        prog_lm = {lm.idx: sum(1 for d in lm.detections if d[0] == cls)
+                   for lm in task.env.store.landmarks}
+        lm_counts = [int(c) if s else prog_lm[int(i)]
+                     for i, c, s in zip(lm_idxs, lm_cnt, lm_sure)]
         seen = [cnt for _, _, _, cnt in got]
         if kind == "count_max":
             gmax = int(cnt_gt.max())
@@ -303,23 +352,27 @@ def _query_fault(kind, cls, task, got, truth, lm_counts) -> str:
     return ""
 
 
-def run(cfg, limits, world_params, probes, rounds, seed: int, *,
+def run(cfg, dets, limits, world_params, probes, rounds, seed: int, *,
         control: bool, scored: bool, trained: bool):
     """``({name: {"value", "limit"}}, the wrong answers)``.
 
+    ``dets``: the configuration's detectors (``traffic.detectors``).
     With ``control`` the reference one precision below the
     configuration's stands in the program's place for the scoring and
     training numbers, which are then compared as the program's are. A
     window that scored nothing (or trained nothing) has no scoring (or
     training) number to compare, and leaves it out; one that did but
-    yields no reading reports None, which fails."""
+    yields no reading reports None, which fails.
+
+    The CPU is the default device here; a detector reference module
+    that runs a network may still place its arrays on the TPU."""
     with jax.default_device(jax.devices("cpu")[0]):
-        return _run(cfg, limits, world_params, probes, rounds, seed,
+        return _run(cfg, dets, limits, world_params, probes, rounds, seed,
                     control, scored, trained)
 
 
-def _run(cfg, limits, world_params, probes, rounds, seed, control, scored,
-         trained):
+def _run(cfg, dets, limits, world_params, probes, rounds, seed, control,
+         scored, trained):
     scenes = {cam: ref.Scene(p) for cam, p in world_params.items()}
     prec = cfg["precision"]
     values = {}
@@ -328,10 +381,13 @@ def _run(cfg, limits, world_params, probes, rounds, seed, control, scored,
                               CONTROL[prec["score"]] if control else None))
     if trained:
         tasks = {id(t.env.trainer): t for r in rounds for t in r["tasks"]}
-        values.update(training(probes.captures, scenes, cfg, probes.answers,
-                               tasks,
+        values.update(training(probes.captures, scenes, cfg, dets,
+                               probes.answers, tasks,
                                CONTROL[prec["train"]] if control else None))
-    wrong = answers(rounds, scenes, cfg, probes.answers)
+    wrong = answers(rounds, scenes, cfg, dets, probes.answers)
     values["answers_wrong"] = len(wrong)
+    for mod in traffic.reference_modules(dets):
+        if hasattr(mod, "numbers"):
+            values.update(mod.numbers(probes, scenes, cfg, seed))
     checks = {k: {"value": v, "limit": limits[k]} for k, v in values.items()}
     return checks, wrong

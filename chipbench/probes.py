@@ -60,15 +60,19 @@ class Probes:
         self.trainer = (None, 0)      # CloudTrainer.train: self, answers seen
         self.demands: List[dict] = []
         self.answers: List[tuple] = []
+        # a detector reference's own counters and captures
+        self.extra: Dict[str, float] = collections.Counter()
+        self.extra_captures: Dict[str, list] = collections.defaultdict(list)
 
     def counts(self) -> dict:
         return {"score_frames": dict(self.score_frames),
                 "train_samples": dict(self.train_samples),
                 "train_steps": self.train_steps,
-                "train_calls": self.train_calls}
+                "train_calls": self.train_calls,
+                "extra": dict(self.extra)}
 
 
-def _hook(owner, attr: str, after) -> None:
+def hook(owner, attr: str, after) -> None:
     """Call ``after(out, *args, **kw)`` after ``owner.attr``, with no span."""
     orig = getattr(owner, attr)
 
@@ -147,7 +151,7 @@ def install(p: Probes) -> None:
     def on_crops(out, bank, idxs, region, size):
         p.last_crops = (out, bank, idxs)
 
-    _hook(FrameBank, "crops", on_crops)
+    hook(FrameBank, "crops", on_crops)
 
     def on_train(trainer, *_a, **_kw):
         p.trainer = (trainer, len(p.answers))
@@ -202,8 +206,10 @@ def install(p: Probes) -> None:
             call = p.call
             st = None
             if call is not None and len(call["steps"]) < STEPS:
-                # xb is donated to the step: copy it first
-                st = dict(xb=jnp.copy(xb), bright=np.array(bright))
+                # xb is donated to the step: copy it first; the labels
+                # are not donated
+                st = dict(xb=jnp.copy(xb), bright=np.array(bright),
+                          yp=rest[0], yc=rest[1])
             out = step(params, m, v, xb, bright, *rest, **kw)
             if st is not None:
                 st["params"] = out[0]       # params are not donated

@@ -48,6 +48,7 @@ class Scene:
     def __init__(self, p: dict):
         self.p = p
         self.classes = p["classes"]
+        self.n_frames = int(p["hours"] * 3600 * p["fps"])
         rng = np.random.default_rng(p["seed"])
         total_s = p["hours"] * 3600
         ev = []
@@ -156,18 +157,18 @@ class Scene:
         n = sum(1 for c in self.detect(idx, det) if c == cls)
         return n > 0, n
 
-    def flow_labels(self, cls: str, det: dict, interval: int,
+    def flow_labels(self, landmarks: Dict[int, Tuple[bool, int]],
                     step_success: float = 0.92, leave_p: float = 0.12,
-                    reach: int = 12) -> Dict[int, Tuple[float, float]]:
-        """``{frame: (label, count)}`` that flow tracking carries from
-        each landmark (every ``interval`` frames, answered by ``det``)
-        into up to ``reach`` frames each way: each step the track holds
-        with ``step_success``, and a tracked object leaves the view with
-        ``leave_p`` (seeded per scene and landmark)."""
-        n = int(self.p["hours"] * 3600 * self.p["fps"])
+                    reach: int = 12) -> Dict[int, Tuple[float, float, int]]:
+        """``{frame: (label, count, landmark)}`` that flow tracking
+        carries from each landmark (``landmarks``: frame -> the landmark
+        detector's presence and count) into up to ``reach`` frames each
+        way: each step the track holds with ``step_success``, and a
+        tracked object leaves the view with ``leave_p`` (seeded per scene
+        and landmark)."""
+        n = self.n_frames
         out = {}
-        for li in range(0, n, interval):
-            label, cnt = self.answer(li, cls, det)
+        for li, (label, cnt) in sorted(landmarks.items()):
             key = f"flow|{self.p['seed']}|{li}".encode()
             rng = np.random.default_rng(zlib.crc32(key) & 0x7FFFFFFF)
             for direction in (-1, 1):
@@ -178,7 +179,7 @@ class Scene:
                         break
                     if lab and rng.uniform() < leave_p:
                         lab, c = False, 0
-                    out[j] = (1.0 if lab else 0.0, float(c))
+                    out[j] = (1.0 if lab else 0.0, float(c), li)
         return out
 
 

@@ -4,7 +4,10 @@
         --trace <0|1> [--control 1]
 
 A cell of ``BENCHMARK.json`` names a configuration (``configs/``) and a
-traffic mix (``mixes/``). A run:
+traffic mix (``mixes/``). The configuration's cloud detector serves
+every query's verification; a detector whose entry names a
+``reference`` brings its reference module (``detectors/``), whose
+``install`` may wrap further calls for its counters and captures. A run:
 
   1. set-up: turns on JAX's persistent compilation cache (every compile
      is written), requires a TPU whose ``device_kind`` has peaks in
@@ -16,7 +19,11 @@ traffic mix (``mixes/``). A run:
      the mix's queries in the seed's order and runs them to their final
      answers. No round starts after ``--seconds``; the window is the
      span of the whole rounds it holds. With ``--trace 1`` the window
-     is traced and the per-layer readers of ``layers/`` report;
+     is traced and the per-layer readers of ``layers/`` report, from the
+     trace's reduction (``xtrace.py``: the harness's and the program's
+     spans, device time by operation and by jitted program), the
+     runtime's dispatch counts, the frames and samples counted, and the
+     window's deltas of the probes' ``extra`` counters;
   3. check: ``check.py`` compares what the window produced with the
      plain reference (``reference.py``), after the device's peak memory
      has been read. ``--control 1`` puts the reference one precision
@@ -36,7 +43,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
+import functools  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -104,6 +111,16 @@ def device_check(jax, cell: dict):
         raise NoChip(str(e)) from None
 
 
+def serve_detector(det) -> None:
+    """Build every query's env with the cloud detector ``det``:
+    ``FleetService.submit`` calls ``make_env`` with its default
+    (YOLOv3) and takes no detector of its own."""
+    from repro.core import query
+    from repro.serving import fleet
+
+    fleet.make_env = functools.partial(query.make_env, cloud_det=det)
+
+
 def run_round(world, cfg, queries, net):
     """One round: a fresh FleetService serves ``queries`` to their final
     answers. Returns per-query host times from submit."""
@@ -150,10 +167,8 @@ def load_readers(bench: dict, cell: str):
     for m in bench["per_layer"]:
         if "workloads" in m and cell not in m["workloads"]:
             continue
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_layer_{len(out)}", HERE / "layers" / f"{m['name']}.py")
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
+        mod = traffic.load_module(HERE / "layers" / f"{m['name']}.py",
+                                  f"chipbench_layer_{len(out)}")
         out[m["name"]] = (m, mod.read)
     return out
 
@@ -185,12 +200,17 @@ def main(argv=None) -> int:
         f"cache_entries_at_start={entries}")
 
     from clock import CompileClock
-    from repro.core.hardware import NetworkModel
+    from repro.core.hardware import DETECTORS, NetworkModel
     from repro.core.runtime import get_runtime
 
     clock = CompileClock()
     pr = probes_mod.Probes()
     probes_mod.install(pr)
+    dets = traffic.detectors(cfg)
+    for mod in traffic.reference_modules(dets):
+        if hasattr(mod, "install"):
+            mod.install(pr)
+    serve_detector(DETECTORS[cfg["cloud_detector"]])
     queries = traffic.round_queries(mix, args.seed)
     world = traffic.build_world(cfg, {cam for cam, _, _ in queries})
     net = NetworkModel(uplink_bytes_per_s=cfg["uplink_bytes_per_s"],
@@ -273,6 +293,8 @@ def main(argv=None) -> int:
     samples = {s: n - work0["train_samples"].get(s, 0)
                for s, n in work1["train_samples"].items()}
     samples = {s: n for s, n in samples.items() if n}
+    counters = {k: n - work0["extra"].get(k, 0)
+                for k, n in work1["extra"].items()}
     log(f"[window] rounds={len(rounds)} window_s={window_s:.6f} "
         f"round_s={[round(r['wall'], 4) for r in rounds]} "
         f"compile_s={c1 - c0:.6f} backend_compiles={n1 - n0} "
@@ -282,7 +304,8 @@ def main(argv=None) -> int:
     log(f"[window] scheduler={sched}")
     log(f"[window] train_steps={work1['train_steps'] - work0['train_steps']}"
         f" train_calls={work1['train_calls'] - work0['train_calls']} "
-        f"frames_by_sig={frames} train_samples_by_sig={samples}")
+        f"frames_by_sig={frames} train_samples_by_sig={samples} "
+        f"counters={counters}")
     log("[window] queries=" + json.dumps(
         [[q["qid"], q["first_s"], q["final_s"]] for q in qs]))
 
@@ -294,8 +317,10 @@ def main(argv=None) -> int:
         size = Path(path).stat().st_size
         red = xtrace.reduce(path, probes_mod.SPAN_NAMES, KERNEL)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        ctx = dict(trace=red, window_s=red["window_s"], dispatch=dispatch,
-                   score_frames=frames, train_samples=samples, peak=peak)
+        prog = red["program"]
+        ctx = dict(trace=red, program=prog, window_s=red["window_s"],
+                   dispatch=dispatch, score_frames=frames,
+                   train_samples=samples, counters=counters, peak=peak)
         metrics = {}
         for name, (entry, read) in load_readers(bench, args.workload).items():
             v = read(ctx)
@@ -303,7 +328,8 @@ def main(argv=None) -> int:
                 metrics[name] = (v, entry["unit"])
         busy = red
         breakdown = {"device_ops": red["device_ops"],
-                     "idle_gaps": red["idle_gaps"]}
+                     "idle_gaps": red["idle_gaps"],
+                     "program_idle_gaps": prog["idle_gaps"]}
         log(f"[trace] read_s={time.perf_counter() - t0:.3f} bytes={size} "
             f"window_s={red['window_s']:.6f} busy_s={red['busy_s']:.6f} "
             f"kernel_s={red['kernel_s']:.6f} devices={red['devices']} "
@@ -312,13 +338,16 @@ def main(argv=None) -> int:
         log(f"[trace] lines={red['lines']}")
         log(f"[trace] device_ops={red['device_ops']}")
         log(f"[trace] idle_gaps={red['idle_gaps']}")
+        log(f"[trace] module_s={red['module_s']}")
+        for k in ("self_s", "counts", "total_s", "idle_by", "idle_gaps"):
+            log(f"[trace] program_{k}={prog[k]}")
 
     # the check runs once the window has closed and peak memory is read
     t0 = time.perf_counter()
     world_params = {cam: traffic.scene_params(v.spec)
                     for cam, (v, _s, _c) in world.items()}
     checks, wrong = check.run(
-        cfg, limits, world_params, pr, rounds, args.seed,
+        cfg, dets, limits, world_params, pr, rounds, args.seed,
         control=bool(args.control), scored=bool(frames),
         trained=bool(samples))
     correct = all(c["value"] is not None and c["value"] <= c["limit"]

@@ -7,7 +7,9 @@ runtime's bucketed layer (the Pallas ``conv_scorer`` on a TPU) and runs
 three Adam steps, inside the harness's ``window``, ``run``, ``train``
 and ``score`` annotations, then sleeps 50 ms inside ``loop`` so the
 trace holds a known idle gap. Prints a summary of the planes, lines and
-the busiest event names, and the stats of a kernel event.
+the busiest event names, the stats of a kernel event, and the program's
+own ``diva.`` spans and the device time of each jitted program as
+``xtrace.reduce`` reads them.
 """
 from __future__ import annotations
 
@@ -19,12 +21,13 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[2]
-sys.path[:0] = [str(ROOT / "src")]
+sys.path[:0] = [str(ROOT / "chipbench"), str(ROOT / "src")]
 
 
 def main(out: str) -> None:
     import jax
     import numpy as np
+    import xtrace
     from jax.profiler import ProfileData, TraceAnnotation
     from repro.core.operators import (OperatorArch, init_operator,
                                       train_operator)
@@ -74,6 +77,10 @@ def main(out: str) -> None:
                 if st:
                     print("    stats of", repr(ev.name), st[:12])
                     break
+    red = xtrace.reduce(path, ("run", "train", "score"), "conv_scorer")
+    for key in ("counts", "self_s", "total_s", "idle_by"):
+        print("program", key, red["program"][key])
+    print("module_s", red["module_s"])
 
 
 if __name__ == "__main__":

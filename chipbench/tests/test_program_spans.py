@@ -1,7 +1,9 @@
-"""``program_spans.py`` on hand-made spans and on the committed traces,
-which were recorded before the program had spans of its own: there the
-program's tables are empty and ``xtrace.reduce`` reads what it read
-when they were recorded."""
+"""The program's own spans as ``xtrace.reduce`` keeps them
+(``summarize``) and the four per-layer metrics that read them
+(``layers/``), on hand-made spans and on the committed traces, which
+were recorded before the program had spans of its own: there the
+program's tables are empty and every other key of ``xtrace.reduce``
+reads what it read when they were recorded."""
 from __future__ import annotations
 
 import sys
@@ -12,8 +14,11 @@ import pytest
 HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE.parent)]
 
-import program_spans  # noqa: E402
+import traffic  # noqa: E402
 import xtrace  # noqa: E402
+
+READINGS = ("adam_step_host_ms", "train_setup_ms", "crop_share",
+            "score_dispatch_ms")
 
 TRACES = {"cpu": HERE / "data" / "trace_cpu.xplane.pb",
           "chip": HERE / "data" / "trace_chip.xplane.pb"}
@@ -55,6 +60,14 @@ PINNED = {
 }
 
 
+def readings(p: dict, window_s: float) -> dict:
+    """The four metrics, each read by its own ``layers/`` file."""
+    ctx = {"program": p, "window_s": window_s}
+    return {name: traffic.load_module(HERE.parent / "layers" / f"{name}.py",
+                                      f"test_layer_{name}").read(ctx)
+            for name in READINGS}
+
+
 def test_summarize_hand_made_spans():
     spans = [(0, 100, "diva.fleet.run", "main"),
              (10, 40, "diva.train", "main"),
@@ -63,7 +76,7 @@ def test_summarize_hand_made_spans():
              (32, 38, "diva.train.step", "main"),
              (50, 60, "diva.score.dispatch", "worker"),
              (-10, 5, "diva.frames.crop", "main")]      # clipped at 0
-    p = program_spans.summarize(spans, (0, 120),
+    p = xtrace.summarize(spans, (0, 120),
                                 [(24, 26), (60, 70), (105, 110)])
     ns = 1e-9
     assert p["counts"] == {"diva.fleet.run": 1, "diva.train": 1,
@@ -83,7 +96,7 @@ def test_summarize_hand_made_spans():
     assert p["idle_by"] == pytest.approx({"diva.fleet.run": 69 * ns,
                                           "diva.train": 24 * ns,
                                           "none": 10 * ns})
-    r = program_spans.readings(p, 120 * ns)
+    r = readings(p, 120 * ns)
     assert r["adam_step_host_ms"] == pytest.approx(8e-6)
     assert r["train_setup_ms"] == pytest.approx(14e-6)
     assert r["crop_share"] == pytest.approx(100 * 5 / 120)
@@ -91,14 +104,14 @@ def test_summarize_hand_made_spans():
 
 
 def test_readings_missing_spans_are_none():
-    p = program_spans.summarize([], (0, 10), [])
+    p = xtrace.summarize([], (0, 10), [])
     assert p["idle_gaps"] == [["none", 10e-9]]
-    assert set(program_spans.readings(p, 1.0).values()) == {None}
+    assert set(readings(p, 1.0).values()) == {None}
 
 
 @pytest.mark.parametrize("trace", sorted(TRACES))
 def test_committed_traces_have_no_program_spans(trace):
-    p = program_spans.reduce(str(TRACES[trace]))
+    p = xtrace.reduce(str(TRACES[trace]), SPANS, "conv_scorer")["program"]
     assert p["self_s"] == p["counts"] == p["total_s"] == {}
     assert set(p["idle_by"]) <= {"none"}
 
@@ -106,37 +119,24 @@ def test_committed_traces_have_no_program_spans(trace):
 @pytest.mark.parametrize("trace", sorted(TRACES))
 def test_harness_reduce_reads_as_recorded(trace):
     r = xtrace.reduce(str(TRACES[trace]), SPANS, "conv_scorer")
-    r.pop("lines")
+    for key in ("lines", "program", "module_s"):   # test_trace.py's
+        r.pop(key)
     r["device_ops"] = [[xtrace.op_name(k), v] for k, v in r["device_ops"]]
     assert r == PINNED[trace]
 
 
 def test_tiny_cell_counts_match_the_harness(tmp_path):
-    """On the tiny CPU cell the program's spans count the Adam steps and
-    the scoring dispatches that the harness counts in the window."""
+    """On the tiny CPU cell, traced, the program's spans count the Adam
+    steps and the scoring dispatches that the harness counts in the
+    window, and the four metrics are reported."""
     import ast
-    import os
-    import subprocess
 
     import tiny
 
-    dst = tiny.make_copy(tmp_path)
-    code = (
-        "import sys\n"
-        f"sys.path[:0] = [{str(dst / 'chipbench')!r}, {str(dst / 'src')!r}]\n"
-        "import program_spans, run, work\n"
-        "run.device_check = lambda jax, cell: "
-        "(jax.devices(), work.peaks('TPU v5 lite'))\n"
-        f"program_spans.main(['--workload', {tiny.CELL!r}, '--seed', "
-        "'3000000001', '--seconds', '2'])\n")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=str(dst / ".jax_cache"))
-    proc = subprocess.run([sys.executable, "-c", code], cwd=dst, env=env,
-                          capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-4000:]
+    result, out, _err = tiny.run(tiny.make_copy(tmp_path), "--trace", "1")
 
     def logged(key):
-        line, = [ln for ln in proc.stdout.splitlines() if f" {key}=" in ln]
+        line, = [ln for ln in out.splitlines() if f" {key}=" in ln]
         return line.split(f" {key}=", 1)[1]
 
     counts = ast.literal_eval(logged("program_counts"))
@@ -144,6 +144,8 @@ def test_tiny_cell_counts_match_the_harness(tmp_path):
     calls = ast.literal_eval(logged("dispatch_stats"))["calls"]
     assert steps > 0 and counts["diva.train.step"] == steps
     assert calls > 0 and counts["diva.score.dispatch"] == calls
-    readings = ast.literal_eval(logged("program_metrics"))
-    assert None not in readings.values()
-    assert '"correct": true' in proc.stdout.splitlines()[-1]
+    for name in READINGS:
+        assert result["metrics"][name]["value"] > 0, name
+    assert len(result["breakdown"]["program_idle_gaps"]) <= 10
+    assert isinstance(ast.literal_eval(logged("module_s")), dict)
+    assert result["correct"] is True

@@ -1,6 +1,6 @@
-"""Configurations and traffic mixes, found by name under ``configs/``
-and ``mixes/``, and the one generator that turns them into a world and
-a round of queries.
+"""Configurations, traffic mixes and detector references, found by name
+under ``configs/``, ``mixes/`` and ``detectors/``, and the one generator
+that turns them into a world and a round of queries.
 
 A configuration (``configs/<name>.json``) fixes the deployment: which
 corpus scenes are cameras (``scenes``), the archive length
@@ -18,6 +18,7 @@ holds more than one query.
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 from pathlib import Path
 from typing import Dict, List, Tuple
@@ -40,6 +41,38 @@ def load_limits(cell: str) -> dict:
     they are set from the readings of that configuration under that
     traffic."""
     return json.loads((HERE / "limits" / f"{cell}.json").read_text())
+
+
+def load_module(path: Path, name: str):
+    """The Python file at ``path``, loaded as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def detectors(cfg: dict) -> Dict[str, dict]:
+    """``{name: detector}`` of the configuration's ``detectors``: each
+    entry with its ``name`` added and, where it names a ``reference``,
+    that module under ``module``. Each module is loaded once, so that
+    its ``install`` and its ``answer`` and ``numbers`` are one module."""
+    mods, out = {}, {}
+    for name, entry in cfg["detectors"].items():
+        det = dict(entry, name=name)
+        ref = entry.get("reference")
+        if ref is not None:
+            if ref not in mods:
+                mods[ref] = load_module(HERE / "detectors" / f"{ref}.py",
+                                        f"chipbench_detector_{ref}")
+            det["module"] = mods[ref]
+        out[name] = det
+    return out
+
+
+def reference_modules(dets: Dict[str, dict]) -> list:
+    """The distinct detector reference modules of ``dets``."""
+    return list({id(d["module"]): d["module"] for d in dets.values()
+                 if "module" in d}.values())
 
 
 def camera_specs(cfg: dict) -> Dict[str, Tuple[object, str]]:
